@@ -1,7 +1,8 @@
 """End-to-end driver: decentralized LM training with NetMax-DP.
 
 Trains a reduced tinyllama-family model (~100M-class scaled down for CPU;
-pass --scale full-100m on real hardware) for a few hundred rounds with:
+pass --scale 100m on real hardware) through the launcher's training loop
+(``repro.launch.train.train``) with:
   * M worker replicas (stacked leading dim — same code path the 512-chip
     dry-run lowers),
   * the Network Monitor refreshing (P, rho) from measured round times,
@@ -12,14 +13,12 @@ pass --scale full-100m on real hardware) for a few hundred rounds with:
 
 import argparse
 import sys
-import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def main():
@@ -36,15 +35,8 @@ def main():
     from dataclasses import replace
 
     from repro.configs.base import get_arch
-    from repro.core import consensus
-    from repro.core.monitor import IterationTimeEMA, NetworkMonitor
-    from repro.core.nettime import LinkTimeModel, Topology
-    from repro.data.synthetic import TokenStream
-    from repro.optim import sgd
-    from repro.train import checkpoint as ckpt
-    from repro.train.trainer import TrainStepConfig, init_stacked, make_train_step
+    from repro.launch.train import train
 
-    M = args.workers
     base = get_arch("tinyllama-1.1b")
     if args.scale == "cpu":
         cfg = replace(
@@ -57,78 +49,14 @@ def main():
                       d_ff=2048, vocab_size=32000, dtype="float32", remat=False)
         seq, bsz = 512, 8
 
-    opt = sgd(momentum=0.9, weight_decay=1e-4)
-    step_fn = jax.jit(make_train_step(cfg, opt, M, TrainStepConfig(gossip_mode=args.gossip)))
-    stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=seq, batch_size=bsz, seed=0)
-
-    topo = Topology(n_workers=M, workers_per_host=max(1, M // 2), hosts_per_pod=1)
-    link = LinkTimeModel(topo, jitter=0.05, seed=3)
-    monitor = NetworkMonitor(M, alpha=args.lr, K=6, R=6)
-    emas = [IterationTimeEMA(M, beta=0.5) for _ in range(M)]
-    d = np.ones((M, M)) - np.eye(M)
-    P = np.where(d > 0, 1.0 / max(M - 1, 1), 0.0)
-    rho = 0.5 / (2 * args.lr * max(M - 1, 1))
-    rng = np.random.default_rng(0)
-
-    start = 0
-    params = opt_state = None
-    if ckpt.latest_step(args.ckpt) is not None:
-        params, opt_state = init_stacked(cfg, opt, M, jax.random.PRNGKey(0))
-        params, opt_state, man, mon_state = ckpt.restore(args.ckpt, params, opt_state)
-        start = man["data_cursor"]["round"]
-        if mon_state:
-            rho = mon_state.get("rho", rho)
-            P = np.asarray(mon_state["P"]) if "P" in mon_state else P
-        print(f"[resume] restored round {start} from {args.ckpt}")
-    else:
-        params, opt_state = init_stacked(cfg, opt, M, jax.random.PRNGKey(0))
-
-    n_params = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)) // M
-    print(f"NetMax-DP: {M} workers x {n_params/1e6:.1f}M params, "
-          f"gossip={args.gossip}, seq={seq}, batch/worker={bsz}")
-
-    t_virtual = 0.0
-    for r in range(start, args.rounds):
-        batch = {
-            k: jnp.stack([jnp.asarray(stream.batch(w, r)[k]) for w in range(M)])
-            for k in ("tokens", "labels")
-        }
-        nb, wts = consensus.sample_round(rng, P, args.lr, rho, d)
-        gossip_in = {
-            "neighbors": jnp.asarray(nb),
-            "weights": jnp.asarray(wts),
-            "lr": jnp.float32(args.lr),
-        }
-        t0 = time.time()
-        params, opt_state, metrics = step_fn(params, opt_state, batch, gossip_in)
-        dt = time.time() - t0
-        # virtual per-worker iteration times (compute overlapped with pull)
-        for i in range(M):
-            ti = link.iteration_time(i, int(nb[i]), now=t_virtual)
-            emas[i].update(int(nb[i]), ti)
-        t_virtual += max(link.iteration_time(i, int(nb[i]), now=t_virtual) for i in range(M))
-
-        if (r + 1) % 10 == 0:
-            monitor.collect({i: emas[i].snapshot() for i in range(M)})
-            pol = monitor.step()
-            if np.isfinite(pol.T_convergence):
-                P, rho = pol.P, pol.rho
-                bad = P.sum(axis=1) <= 0
-                P[bad] = np.where(d[bad] > 0, 1.0 / max(M - 1, 1), 0.0)
-            print(f"  [monitor] round {r+1}: lambda2={pol.lambda2:.4f} rho={rho:.3f}")
-
-        if (r + 1) % 5 == 0 or r == start:
-            print(f"round {r+1:4d}  loss={float(metrics['loss']):.4f}  "
-                  f"per-worker={np.round(np.asarray(metrics['loss_per_worker']), 3)}  "
-                  f"step={dt:.2f}s")
-
-        if (r + 1) % args.ckpt_every == 0:
-            ckpt.save(
-                args.ckpt, r + 1, params, opt_state,
-                monitor_state={"rho": float(rho), "P": P.tolist()},
-                data_cursor={"round": r + 1},
-            )
-            print(f"  [checkpoint] saved round {r+1}")
+    run = train(
+        cfg, workers=args.workers, rounds=args.rounds, seq=seq,
+        batch_per_worker=bsz, lr=args.lr,
+        algo="local" if args.gossip == "none" else "netmax",
+        gossip="gather" if args.gossip == "none" else args.gossip,
+        ckpt_dir=args.ckpt, ckpt_every=args.ckpt_every, log_every=5,
+    )
+    params = run.params
 
     print("\nConsensus check (replica max-deviation per leaf, should be small):")
     dev = max(

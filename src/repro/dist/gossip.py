@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -70,9 +69,8 @@ def pull_ppermute(params, perm, mesh, worker_axes, specs=None):
             lambda x: jax.lax.ppermute(x, axis_name=axis_name, perm=pairs), tree
         )
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=(specs,), out_specs=specs,
-        check_rep=False,
     )(params)
 
 

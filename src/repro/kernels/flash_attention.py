@@ -7,8 +7,10 @@ VMEM scratch across KV steps.  Grid: (batch*kv_heads, q_blocks, kv_blocks);
 the KV dimension iterates fastest so the (acc, m, l) scratch carries across
 kv steps for one (bh, q_block).
 
-Validated against ref.reference_attention in interpret mode (CPU); compiled
-path targets real TPUs.
+Checked against ref.reference_attention in interpret mode (CPU).  The v5e
+compiler accepts it at qwen1.5-0.5b widths (MHA, S=4096;
+tests/test_tpu_compile.py), but it has not run on a chip, and no model path
+calls it: models/attention.py runs its own XLA chunked attention.
 """
 
 from __future__ import annotations

@@ -16,7 +16,9 @@ Two entry points share the kernel body:
 * ``gossip_mix``       — one replica, scalar w (the trainer's per-slice path)
 * ``gossip_mix_rows``  — a stacked (R, ...) block with per-row weights, one
   grid row per worker/cohort member (the batched engine / stacked trainer
-  path; w lives in SMEM indexed by the row program id).
+  path; w lives in SMEM indexed by the row program id).  Rows are tiled as
+  (k, 128) with k a multiple of the dtype's sublane count, which the v5e
+  compiler accepts for any R (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -71,8 +73,11 @@ def gossip_mix(x, u, pulled, w, *, interpret: bool = False, block: int = _BLOCK)
     return out[:n].reshape(shape)
 
 
+_LANES = 128
+
+
 def _mix_rows_kernel(x_ref, u_ref, p_ref, w_ref, o_ref):
-    w = w_ref[0]  # this grid row's weight (SMEM)
+    w = w_ref[pl.program_id(0)]  # this grid row's weight (SMEM)
     x_half = x_ref[...].astype(jnp.float32) + u_ref[...].astype(jnp.float32)
     out = (1.0 - w) * x_half + w * p_ref[...].astype(jnp.float32)
     o_ref[...] = out.astype(o_ref.dtype)
@@ -83,37 +88,37 @@ def gossip_mix_rows(x, u, pulled, w, *, interpret: bool = False, block: int = _B
     """Per-row fused mix: out[r] = (1-w[r])*(x[r]+u[r]) + w[r]*pulled[r].
 
     x/u/pulled: (R, ...) same-shape stacked arrays (any dtype); w: (R,) f32.
-    Grid is (rows, tiles): each program streams one 1-D tile of one row
-    through VMEM with that row's scalar weight prefetched into SMEM, so the
-    batched engine mixes a whole cohort in a single kernel launch instead of
-    R separate ``gossip_mix`` calls.
+    Each row is laid out as (sublanes, 128) and the grid is (rows, tiles):
+    each program streams one (k, 128) tile of one row through VMEM, the row
+    dim squeezed out of the block, with all R weights resident in SMEM.  k
+    is a multiple of the dtype's sublane count (8 for f32, 16 for bf16), so
+    the chip's (8, 128) tiling rule holds for any R.  The batched engine
+    thus mixes a whole cohort in one kernel launch instead of R separate
+    ``gossip_mix`` calls.
     """
     shape, dtype = x.shape, x.dtype
     R = shape[0]
     n = x.size // max(R, 1)
-    # Shrink the tile for small rows (lane-dim 128-aligned) so padding never
-    # dominates; n is static under jit, so this is trace-time arithmetic.
-    block = min(block, max(128, ((n + 127) // 128) * 128))
-    xf, uf, pf = (a.reshape(R, -1) for a in (x, u, pulled))
-    pad = (-n) % block
-    if pad:
-        xf = jnp.pad(xf, ((0, 0), (0, pad)))
-        uf = jnp.pad(uf, ((0, 0), (0, pad)))
-        pf = jnp.pad(pf, ((0, 0), (0, pad)))
-    nb = (n + pad) // block
+    sub = max(8, 32 // jnp.dtype(dtype).itemsize)
+    rows = -(-n // _LANES)
+    # Shrink the tile for small rows so padding never dominates; n is static
+    # under jit, so this is trace-time arithmetic.
+    k = min(max(sub, block // _LANES // sub * sub), -(-rows // sub) * sub)
+    nb = -(-rows // k)
+    pad = nb * k * _LANES - n
+    xf, uf, pf = (
+        jnp.pad(a.reshape(R, n), ((0, 0), (0, pad))).reshape(R, nb * k, _LANES)
+        for a in (x, u, pulled)
+    )
     wv = jnp.asarray(w, jnp.float32).reshape(R)
 
+    tile = pl.BlockSpec((None, k, _LANES), lambda r, b: (r, b, 0))
     out = pl.pallas_call(
         _mix_rows_kernel,
         grid=(R, nb),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda r, b: (r, b)),
-            pl.BlockSpec((1, block), lambda r, b: (r, b)),
-            pl.BlockSpec((1, block), lambda r, b: (r, b)),
-            pl.BlockSpec((1,), lambda r, b: (r,), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda r, b: (r, b)),
-        out_shape=jax.ShapeDtypeStruct((R, n + pad), dtype),
+        in_specs=[tile, tile, tile, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((R, nb * k, _LANES), dtype),
         interpret=interpret,
     )(xf, uf, pf, wv)
-    return out[:, :n].reshape(shape)
+    return out.reshape(R, -1)[:, :n].reshape(shape)

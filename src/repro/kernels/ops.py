@@ -5,7 +5,9 @@
   * False -> pure-jnp reference (XLA; used for dry-run lowering on CPU)
   * "interpret" -> Pallas interpret mode (CPU correctness testing)
 
-Default: Pallas on TPU backends, reference elsewhere.
+Default: Pallas on TPU backends, reference elsewhere.  ``rwkv_scan`` does
+not compile for a TPU (its docstring says why), so ``rwkv`` needs
+``use_pallas=False`` there.
 """
 
 from __future__ import annotations

@@ -1,6 +1,9 @@
-"""Pallas TPU kernel: chunked RWKV-6 WKV recurrence.
+"""Pallas kernel: chunked RWKV-6 WKV recurrence.  Runs in interpret mode only:
+the TPU compiler refuses it ("Unimplemented primitive in Pallas TPU lowering:
+cumsum", for a described v5e), and no model path calls it (models/rwkv.py runs
+the jnp scan).
 
-TPU adaptation of the data-dependent-decay recurrence (DESIGN.md §3): the
+Chunked form of the data-dependent-decay recurrence (DESIGN.md §3): the
 per-token update
 
     y_t   = r_t (S_{t-1} + u k_t v_t^T)

@@ -32,9 +32,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     )
 
 
+def make_worker_mesh(devices, tp: int = 1):
+    """(data, model) mesh over ``devices`` with Auto axis types: NetMax
+    workers enumerate 'data', each worker's replica spans ``tp`` devices."""
+    devs = np.asarray(devices).reshape(len(devices) // tp, tp)
+    auto = (jax.sharding.AxisType.Auto,) * 2
+    return jax.sharding.Mesh(devs, ("data", "model"), axis_types=auto)
+
+
 def make_debug_mesh(n_workers: int = 2, tp: int = 1):
     """Tiny mesh for subprocess SPMD tests (host platform devices)."""
-    return jax.make_mesh((n_workers, tp), ("data", "model"))
+    return make_worker_mesh(jax.devices()[: n_workers * tp], tp)
 
 
 def worker_count(mesh, worker_axes: tuple) -> int:

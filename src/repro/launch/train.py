@@ -1,124 +1,224 @@
-"""Production training driver: ``python -m repro.launch.train --arch <id> ...``
+"""Training driver: ``python -m repro.launch.train --arch <id> ...``
 
-Composes the full stack: arch config -> mesh/sharding plan -> NetMax trainer
-(or a baseline algorithm) -> Network Monitor -> checkpoint/restart.  On real
-hardware this runs under the production mesh; on CPU it runs reduced configs
-for verification (--reduced).
+Composes the full stack: arch config -> worker mesh -> NetMax trainer (or a
+baseline algorithm) -> Network Monitor -> checkpoint/restart.  ``train`` is
+the one training loop: ``main`` calls it from the command line and
+``chip_smoke.py`` calls it at full width on a TPU.  Only ``--reduced``
+shrinks a config, except that a CPU backend always trains the reduced config
+(tests), and says so.
 
-The same step function the multi-pod dry-run lowers is executed here — there
-is exactly one trainer code path.
+Given more than one device, ``train`` builds a (data, model) mesh over them
+and shards the stacked worker axis over 'data'; ``main`` gives it the largest
+device count that divides the worker count.  The same step function the
+multi-pod dry-run lowers is executed here — there is exactly one trainer
+code path.
 """
 
 from __future__ import annotations
 
 import argparse
+import math
 import time
+from dataclasses import dataclass, field
 
+import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from repro.algos import get_algorithm
+from repro.configs.base import ArchConfig, get_arch
+from repro.core import consensus
+from repro.core.monitor import IterationTimeEMA, NetworkMonitor
+from repro.core.nettime import LinkTimeModel, Topology
+from repro.data.synthetic import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_worker_mesh
+from repro.optim import sgd
+from repro.train import checkpoint as ckpt
+from repro.train.trainer import TrainStepConfig, init_stacked, make_train_step
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True)
-    ap.add_argument("--rounds", type=int, default=100)
-    ap.add_argument("--workers", type=int, default=4)
-    ap.add_argument("--reduced", action="store_true",
-                    help="CPU-scale reduced config (default on cpu backend)")
-    ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--batch-per-worker", type=int, default=4)
-    ap.add_argument("--lr", type=float, default=0.02)
-    ap.add_argument("--algo", default="netmax",
-                    choices=["netmax", "allreduce", "prague", "local"])
-    ap.add_argument("--gossip", default="gather",
-                    choices=["gather", "masked_psum", "ppermute"])
-    ap.add_argument("--ckpt", default=None)
-    ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--monitor-every", type=int, default=10)
-    ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+@dataclass
+class RoundLog:
+    round: int  # 1-based
+    loss: float
+    wall_s: float  # dispatch to block_until_ready, host clock
+    neighbors: np.ndarray  # (M,) i32, the round's gossip draw
+    weights: np.ndarray  # (M,) f32
 
-    import jax
-    import jax.numpy as jnp
 
-    from repro.configs.base import get_arch
-    from repro.core import consensus
-    from repro.core.monitor import IterationTimeEMA, NetworkMonitor
-    from repro.core.nettime import LinkTimeModel, Topology
-    from repro.data.synthetic import TokenStream
-    from repro.optim import sgd
-    from repro.train import checkpoint as ckpt
-    from repro.train.trainer import TrainStepConfig, init_stacked, make_train_step
+@dataclass
+class TrainRun:
+    params: object  # stacked (M, ...) leaves, on the run's devices
+    opt_state: object
+    mesh: object  # None on one device
+    params_per_worker: int
+    compile_s: float = 0.0  # lower + compile of the round program
+    rounds: list[RoundLog] = field(default_factory=list)
 
-    M = args.workers
-    cfg = get_arch(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
-        cfg = cfg.reduced()
 
+def make_step(cfg: ArchConfig, M: int, *, algo: str = "netmax",
+              gossip: str = "gather", mesh=None):
+    """(optimizer, jitted round) for M stacked workers.  The round donates
+    nothing: on a v5e, donating params and optimizer state makes XLA copy
+    them for the pull, and the program needs more HBM, not less (12.44 GiB
+    against 12.23 for qwen1.5-0.5b at M=2)."""
     opt = sgd(momentum=0.9, weight_decay=1e-4)
-    from repro.algos import get_algorithm
-
-    if args.algo == "prague":
-        algo = get_algorithm("prague", trainer_groups=max(2, M // 2))
+    if algo == "prague":
+        algorithm = get_algorithm("prague", trainer_groups=max(2, M // 2))
     else:
-        algo = get_algorithm("netmax" if args.algo == "local" else args.algo)
+        algorithm = get_algorithm("netmax" if algo == "local" else algo)
     step_cfg = TrainStepConfig(
-        gossip_mode="none" if args.algo in ("allreduce", "local") else args.gossip,
+        gossip_mode="none" if algo in ("allreduce", "local") else gossip,
     )
-    step_fn = jax.jit(make_train_step(cfg, opt, M, algo, step_cfg))
-    stream = TokenStream(cfg.vocab_size, args.seq, args.batch_per_worker, seed=0)
+    worker_axes = ("data",) if mesh is not None else ()
+    step = make_train_step(cfg, opt, M, algorithm, step_cfg, mesh=mesh,
+                           worker_axes=worker_axes)
+    return opt, jax.jit(step)
+
+
+def train(
+    cfg: ArchConfig,
+    *,
+    workers: int,
+    rounds: int,
+    devices=None,
+    seq: int = 128,
+    batch_per_worker: int = 4,
+    lr: float = 0.02,
+    algo: str = "netmax",
+    gossip: str = "gather",
+    seed: int = 0,
+    ckpt_dir: str | None = None,
+    ckpt_every: int = 50,
+    monitor_every: int = 10,
+    log_every: int = 10,
+) -> TrainRun:
+    """Train ``workers`` NetMax replicas of ``cfg`` for ``rounds`` rounds on
+    ``devices`` (default: the first device).  Every round ends in
+    ``block_until_ready``; its loss, wall time and gossip draw are kept."""
+    M = workers
+    devices = list(devices) if devices is not None else jax.devices()[:1]
+    if M % len(devices):
+        raise ValueError(f"{M} workers do not divide over {len(devices)} devices")
+    if len(devices) > 1:
+        mesh = make_worker_mesh(devices)
+        state_sh = NamedSharding(mesh, PartitionSpec("data"))
+        repl_sh = NamedSharding(mesh, PartitionSpec())
+    else:
+        mesh = None
+        state_sh = repl_sh = SingleDeviceSharding(devices[0])
+
+    opt, step_fn = make_step(cfg, M, algo=algo, gossip=gossip, mesh=mesh)
+    stream = TokenStream(cfg.vocab_size, seq, batch_per_worker, seed=seed)
 
     topo = Topology(M, workers_per_host=max(1, M // 2), hosts_per_pod=1)
     link = LinkTimeModel(topo, jitter=0.05, seed=1)
-    monitor = NetworkMonitor(M, alpha=args.lr, K=6, R=6)
+    monitor = NetworkMonitor(M, alpha=lr, K=6, R=6)
     emas = [IterationTimeEMA(M, beta=0.5) for _ in range(M)]
     d = np.ones((M, M)) - np.eye(M)
     P = np.where(d > 0, 1.0 / max(M - 1, 1), 0.0)
-    rho = 0.5 / (2 * args.lr * max(M - 1, 1))
-    rng = np.random.default_rng(0)
+    rho = 0.5 / (2 * lr * max(M - 1, 1))
+    rng = np.random.default_rng(seed)
 
     start = 0
-    params, opt_state = init_stacked(cfg, opt, M, jax.random.PRNGKey(0))
-    if args.ckpt and ckpt.latest_step(args.ckpt) is not None:
-        params, opt_state, man, mon = ckpt.restore(args.ckpt, params, opt_state)
+    init = jax.jit(lambda k: init_stacked(cfg, opt, M, k), out_shardings=state_sh)
+    params, opt_state = init(jax.random.PRNGKey(seed))
+    if ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
+        params, opt_state, man, mon = ckpt.restore(ckpt_dir, params, opt_state)
+        params, opt_state = jax.device_put((params, opt_state), state_sh)
         start = man["data_cursor"].get("round", 0)
         if mon and "P" in mon:
             P, rho = np.asarray(mon["P"]), mon.get("rho", rho)
         print(f"[resume] round {start}")
 
     n = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)) // M
-    print(f"[{args.algo}] arch={cfg.name} M={M} params/worker={n/1e6:.1f}M "
-          f"gossip={step_cfg.gossip_mode}")
+    run = TrainRun(params=None, opt_state=None, mesh=mesh, params_per_worker=n)
+    print(f"[{algo}] arch={cfg.name} M={M} params/worker={n/1e6:.1f}M "
+          f"gossip={gossip} devices={len(devices)} batch/worker={batch_per_worker}x{seq}")
 
+    compiled = None
     t_virt = 0.0
-    for r in range(start, args.rounds):
-        batch = {
-            k: jnp.stack([jnp.asarray(stream.batch(w, r)[k]) for w in range(M)])
-            for k in ("tokens", "labels")
-        }
-        nb, wts = consensus.sample_round(rng, P, args.lr, rho, d)
-        gi = {"neighbors": jnp.asarray(nb), "weights": jnp.asarray(wts),
-              "lr": jnp.float32(args.lr)}
-        t0 = time.time()
-        params, opt_state, m = step_fn(params, opt_state, batch, gi)
+    for r in range(start, rounds):
+        batch = jax.device_put(
+            {k: np.stack([stream.batch(w, r)[k] for w in range(M)])
+             for k in ("tokens", "labels")},
+            state_sh,
+        )
+        nb, wts = consensus.sample_round(rng, P, lr, rho, d)
+        gi = jax.device_put({"neighbors": nb, "weights": wts,
+                             "lr": np.float32(lr)}, repl_sh)
+        if compiled is None:
+            t0 = time.perf_counter()
+            compiled = step_fn.lower(params, opt_state, batch, gi).compile()
+            run.compile_s = time.perf_counter() - t0
+            print(f"compiled round program in {run.compile_s:.2f}s")
+        t0 = time.perf_counter()
+        params, opt_state, m = compiled(params, opt_state, batch, gi)
+        jax.block_until_ready((params, opt_state, m))
+        wall = time.perf_counter() - t0
+        loss = float(m["loss"])
+        run.rounds.append(RoundLog(r + 1, loss, wall, nb, wts))
         for i in range(M):
             emas[i].update(int(nb[i]), link.iteration_time(i, int(nb[i]), now=t_virt))
         t_virt += max(link.iteration_time(i, int(nb[i]), now=t_virt) for i in range(M))
 
-        if args.algo == "netmax" and (r + 1) % args.monitor_every == 0:
+        if algo == "netmax" and (r + 1) % monitor_every == 0:
             monitor.collect({i: emas[i].snapshot() for i in range(M)})
             pol = monitor.step()
             if np.isfinite(pol.T_convergence):
                 P, rho = pol.P, pol.rho
                 bad = P.sum(axis=1) <= 0
                 P[bad] = np.where(d[bad] > 0, 1.0 / max(M - 1, 1), 0.0)
-        if (r + 1) % args.log_every == 0 or r == start:
-            print(f"round {r+1:5d} loss={float(m['loss']):.4f} "
-                  f"step_wall={time.time()-t0:.2f}s virt={t_virt:.1f}s")
-        if args.ckpt and (r + 1) % args.ckpt_every == 0:
-            ckpt.save(args.ckpt, r + 1, params, opt_state,
+            print(f"  [monitor] round {r+1}: lambda2={pol.lambda2:.4f} rho={rho:.4f}")
+        if (r + 1) % log_every == 0 or r == start:
+            print(f"round {r+1:5d} loss={loss:.4f} step_wall={wall:.4f}s "
+                  f"virt={t_virt:.1f}s")
+        if ckpt_dir and (r + 1) % ckpt_every == 0:
+            ckpt.save(ckpt_dir, r + 1, params, opt_state,
                       monitor_state={"rho": float(rho), "P": P.tolist()},
                       data_cursor={"round": r + 1})
+            print(f"  [checkpoint] saved round {r+1}")
 
+    run.params, run.opt_state = params, opt_state
+    return run
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", required=True)
+    ap.add_argument("--rounds", type=int, default=100)
+    ap.add_argument("--workers", type=int, default=4)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config (always on a cpu backend)")
+    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--batch-per-worker", type=int, default=4)
+    ap.add_argument("--lr", type=float, default=0.02)
+    ap.add_argument("--algo", default="netmax",
+                    choices=["netmax", "allreduce", "prague", "local"])
+    ap.add_argument("--gossip", default="gather", choices=["gather", "masked_psum"])
+    ap.add_argument("--ckpt", default=None)
+    ap.add_argument("--ckpt-every", type=int, default=50)
+    ap.add_argument("--monitor-every", type=int, default=10)
+    ap.add_argument("--log-every", type=int, default=10)
+    args = ap.parse_args(argv)
+
+    enable_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.reduced or jax.default_backend() == "cpu":
+        if not args.reduced:
+            print(f"[train] {jax.default_backend()} backend: training the "
+                  f"reduced {cfg.name} config")
+        cfg = cfg.reduced()
+    devices = jax.devices()
+    train(
+        cfg, workers=args.workers, rounds=args.rounds,
+        devices=devices[: math.gcd(args.workers, len(devices))],
+        seq=args.seq, batch_per_worker=args.batch_per_worker, lr=args.lr,
+        algo=args.algo, gossip=args.gossip, ckpt_dir=args.ckpt,
+        ckpt_every=args.ckpt_every, monitor_every=args.monitor_every,
+        log_every=args.log_every,
+    )
     print("done.")
 
 

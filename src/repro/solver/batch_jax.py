@@ -20,7 +20,7 @@ numpy path it is cold-start by design (no warm bases in or out), and it
 agrees with the serial solver to solver tolerance, not bit-for-bit:
 callers that need bit-stable policies keep the serial path.
 
-Everything runs in float64 under a local ``enable_x64`` scope — the
+Everything runs in float64 under a local ``jax.enable_x64`` scope — the
 simplex is not a float32 algorithm — so importing this module never
 flips global jax precision for the rest of the process.
 """
@@ -354,11 +354,11 @@ def solve_lp_batch_jax(
     Drop-in for ``repro.solver.batch.solve_lp_batch`` with identical
     call/return conventions (one ``LPResult`` per instance, cold-start,
     sparse ``A`` densified), executed as one jitted two-phase lockstep
-    simplex in float64 under a local ``enable_x64`` scope.  Compilation
+    simplex in float64 under a local ``jax.enable_x64`` scope.  Compilation
     is cached per (shape, caps); repeat sweeps over the same layout —
     the Eq.-14 grid shape — pay tracing once.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     c = np.asarray(c, dtype=np.float64)
     if hasattr(A, "toarray") and not isinstance(A, np.ndarray):
@@ -390,7 +390,7 @@ def solve_lp_batch_jax(
         ub = np.concatenate([ub, np.ones((pad, n))])
         live = np.concatenate([live, np.zeros(pad, dtype=bool)])
 
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _get_solver(int(max_iter), int(refactor_every))
         x, status, pivots = fn(c, A, b, lb, ub, live)
         x = np.asarray(x)[:S]
