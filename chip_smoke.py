@@ -91,9 +91,10 @@ def one_chip(cfg, device, seed: int = 0) -> list[str]:
     """M=2 NetMax replicas of ``cfg`` on ``device``; returns the failures."""
     run = _train(cfg, 2, [device], seed)
     print(f"params per worker: {run.params_per_worker}")
-    print(f"compile: {run.compile_s:.2f}s")
+    print(f"compile: {run.setup['compile']:.2f}s")
     for r in run.rounds:
-        print(f"round {r.round} loss={r.loss:.6f} wall={r.wall_s:.6f}s "
+        wall = r.spans["dispatch"] + r.spans["device_wait"]
+        print(f"round {r.round} loss={r.loss:.6f} wall={wall:.6f}s "
               f"neighbors={r.neighbors.tolist()} weights={r.weights.tolist()}")
     stats = device.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
@@ -146,7 +147,7 @@ def four_chips(cfg, devices, seed: int = 0) -> list[str]:
     failures = []
     run = _train(cfg, 4, devices, seed)
     print(f"params per worker: {run.params_per_worker}")
-    print(f"compile: {run.compile_s:.2f}s")
+    print(f"compile: {run.setup['compile']:.2f}s")
     failures += _loss_checks(run, cfg.vocab_size)
 
     placement = set()

@@ -88,18 +88,3 @@ def from_record(rec: dict, shape) -> Roofline | None:
         dominant=dom,
     )
 
-
-def fix_suggestion(r: Roofline) -> str:
-    """One sentence on what would move the dominant term down."""
-    if r.dominant == "compute":
-        if r.useful_ratio < 0.5:
-            return ("compute-bound with low useful ratio: cut remat recompute "
-                    "(policy: save attention outputs) and skip fully-masked "
-                    "causal KV blocks")
-        return "compute-bound near useful peak: only larger per-chip batch helps"
-    if r.dominant == "memory":
-        return ("memory-bound: fuse elementwise chains (gossip_mix kernel), "
-                "larger matmul tiles, bf16 loss accumulators, widen per-chip batch")
-    return ("collective-bound: shrink TP degree for this model size, switch "
-            "gossip to matched ppermute, overlap pulls with grad compute, "
-            "or compress pulls (top-k/int8)")

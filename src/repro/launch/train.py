@@ -17,6 +17,8 @@ code path.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import gc
 import math
 import time
 from dataclasses import dataclass, field
@@ -42,9 +44,24 @@ from repro.train.trainer import TrainStepConfig, init_stacked, make_train_step
 class RoundLog:
     round: int  # 1-based
     loss: float
-    wall_s: float  # dispatch to block_until_ready, host clock
+    # host seconds of each phase that ran this round (``_span`` names:
+    # stage, draw, dispatch, device_wait, readback, ema, log; monitor and
+    # checkpoint on the rounds that refresh or save), and of what interrupted
+    # them (``NESTED``: gc, backend_compile) when any did
+    spans: dict[str, float]
     neighbors: np.ndarray  # (M,) i32, the round's gossip draw
     weights: np.ndarray  # (M,) f32
+
+
+@dataclass
+class Refresh:
+    """One Network Monitor refresh of P."""
+    round: int
+    ms: float  # host time of collect + step + the repair of P
+    n_solves: int  # simplex runs of the sweep
+    n_pivots: int
+    n_warm_used: int
+    applied: bool  # the LP gave a policy and P changed
 
 
 @dataclass
@@ -53,8 +70,82 @@ class TrainRun:
     opt_state: object
     mesh: object  # None on one device
     params_per_worker: int
-    compile_s: float = 0.0  # lower + compile of the round program
+    setup: dict[str, float] = field(default_factory=dict)  # init, compile seconds
+    compiles: int = 0  # backend compiles while train() ran, of any program
+    gc: dict[int, tuple[int, float]] = field(default_factory=dict)  # gen -> (count, s)
+    refreshes: list[Refresh] = field(default_factory=list)
     rounds: list[RoundLog] = field(default_factory=list)
+
+
+@contextlib.contextmanager
+def _span(name: str, rec: dict):
+    """A host span: a profiler annotation, on the device trace's clock when a
+    trace is being taken (a cheap check when none is), whose seconds are
+    added to ``rec[name]``."""
+    with jax.profiler.TraceAnnotation(name):
+        t0 = time.perf_counter()
+        yield
+        rec[name] = rec.get(name, 0.0) + time.perf_counter() - t0
+
+
+# Seconds that interrupt a phase rather than follow it: they overlap the
+# phase they fell in, so a round's length is the sum of its other spans.
+NESTED = ("gc", "backend_compile")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _Watch:
+    """While entered: each collection of Python's collector is a ``gc``
+    span, counted in ``gc`` = {generation: (collections, seconds)}; each
+    backend compile is counted in ``compiles``.  Both add their seconds to
+    ``rec`` (the record of the round or set-up then running) under ``gc``
+    and ``backend_compile``."""
+
+    def __init__(self, rec: dict):
+        self.rec = rec
+        self.gc: dict[int, tuple[int, float]] = {}
+        self.compiles = 0
+        self._open: list = []
+
+    def __enter__(self):
+        gc.callbacks.append(self._collection)
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self._collection)
+        jax.monitoring.unregister_event_duration_listener(self._event)
+
+    def _add(self, key: str, secs: float):
+        self.rec[key] = self.rec.get(key, 0.0) + secs
+
+    def _collection(self, phase, info):
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation("gc")
+            ann.__enter__()
+            self._open.append((ann, time.perf_counter()))
+        elif self._open:
+            ann, t0 = self._open.pop()
+            ann.__exit__(None, None, None)
+            secs = time.perf_counter() - t0
+            n, total = self.gc.get(info["generation"], (0, 0.0))
+            self.gc[info["generation"]] = (n + 1, total + secs)
+            self._add("gc", secs)
+
+    def _event(self, event: str, secs: float, **kw):
+        if event == _COMPILE_EVENT:
+            self.compiles += 1
+            self._add("backend_compile", secs)
+
+
+def _slowest(rounds: list[RoundLog]) -> str:
+    """The five longest rounds, each with every span it recorded (ms)."""
+    def length(g):
+        return sum(v for n, v in g.spans.items() if n not in NESTED)
+    return "; ".join(
+        f"{g.round} {length(g) * 1e3:.2f}ms: " + " ".join(
+            f"{n}={v * 1e3:.2f}" for n, v in g.spans.items())
+        for g in sorted(rounds, key=length, reverse=True)[:5])
 
 
 def make_step(cfg: ArchConfig, M: int, *, algo: str = "netmax",
@@ -96,7 +187,12 @@ def train(
 ) -> TrainRun:
     """Train ``workers`` NetMax replicas of ``cfg`` for ``rounds`` rounds on
     ``devices`` (default: the first device).  Every round ends in
-    ``block_until_ready``; its loss, wall time and gossip draw are kept."""
+    ``block_until_ready``; its loss, gossip draw and the host seconds of its
+    phases are kept.  Each round runs under a ``round`` step annotation and
+    each phase under a span of its name (``_span``), so a profiler trace
+    taken meanwhile shows them on the device's clock; Python's collections
+    show as ``gc`` spans.  The run's output ends with its slowest rounds and
+    their spans."""
     M = workers
     devices = list(devices) if devices is not None else jax.devices()[:1]
     if M % len(devices):
@@ -121,65 +217,93 @@ def train(
     rho = 0.5 / (2 * lr * max(M - 1, 1))
     rng = np.random.default_rng(seed)
 
-    start = 0
-    init = jax.jit(lambda k: init_stacked(cfg, opt, M, k), out_shardings=state_sh)
-    params, opt_state = init(jax.random.PRNGKey(seed))
-    if ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
-        params, opt_state, man, mon = ckpt.restore(ckpt_dir, params, opt_state)
-        params, opt_state = jax.device_put((params, opt_state), state_sh)
-        start = man["data_cursor"].get("round", 0)
-        if mon and "P" in mon:
-            P, rho = np.asarray(mon["P"]), mon.get("rho", rho)
-        print(f"[resume] round {start}")
+    setup: dict[str, float] = {}
+    with _Watch(setup) as watch:
+        start = 0
+        with _span("init", setup):
+            init = jax.jit(lambda k: init_stacked(cfg, opt, M, k),
+                           out_shardings=state_sh)
+            params, opt_state = init(jax.random.PRNGKey(seed))
+            if ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
+                params, opt_state, man, mon = ckpt.restore(ckpt_dir, params, opt_state)
+                params, opt_state = jax.device_put((params, opt_state), state_sh)
+                start = man["data_cursor"].get("round", 0)
+                if mon and "P" in mon:
+                    P, rho = np.asarray(mon["P"]), mon.get("rho", rho)
+                print(f"[resume] round {start}")
 
-    n = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)) // M
-    run = TrainRun(params=None, opt_state=None, mesh=mesh, params_per_worker=n)
-    print(f"[{algo}] arch={cfg.name} M={M} params/worker={n/1e6:.1f}M "
-          f"gossip={gossip} devices={len(devices)} batch/worker={batch_per_worker}x{seq}")
+        n = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(params)) // M
+        run = TrainRun(params=None, opt_state=None, mesh=mesh, params_per_worker=n,
+                       setup=setup)
+        print(f"[{algo}] arch={cfg.name} M={M} params/worker={n/1e6:.1f}M "
+              f"gossip={gossip} devices={len(devices)} batch/worker={batch_per_worker}x{seq}")
 
-    compiled = None
-    t_virt = 0.0
-    for r in range(start, rounds):
-        batch = jax.device_put(
-            {k: np.stack([stream.batch(w, r)[k] for w in range(M)])
-             for k in ("tokens", "labels")},
-            state_sh,
-        )
-        nb, wts = consensus.sample_round(rng, P, lr, rho, d)
-        gi = jax.device_put({"neighbors": nb, "weights": wts,
-                             "lr": np.float32(lr)}, repl_sh)
-        if compiled is None:
-            t0 = time.perf_counter()
-            compiled = step_fn.lower(params, opt_state, batch, gi).compile()
-            run.compile_s = time.perf_counter() - t0
-            print(f"compiled round program in {run.compile_s:.2f}s")
-        t0 = time.perf_counter()
-        params, opt_state, m = compiled(params, opt_state, batch, gi)
-        jax.block_until_ready((params, opt_state, m))
-        wall = time.perf_counter() - t0
-        loss = float(m["loss"])
-        run.rounds.append(RoundLog(r + 1, loss, wall, nb, wts))
-        for i in range(M):
-            emas[i].update(int(nb[i]), link.iteration_time(i, int(nb[i]), now=t_virt))
-        t_virt += max(link.iteration_time(i, int(nb[i]), now=t_virt) for i in range(M))
+        compiled = None
+        t_virt = 0.0
+        for r in range(start, rounds):
+            # One round, and under it the phases of the loop body, back to back.
+            rec = watch.rec = {}
+            with jax.profiler.StepTraceAnnotation("round", step_num=r + 1):
+                with _span("stage", rec):
+                    batch = jax.device_put(
+                        {k: np.stack([stream.batch(w, r)[k] for w in range(M)])
+                         for k in ("tokens", "labels")},
+                        state_sh,
+                    )
+                with _span("draw", rec):
+                    nb, wts = consensus.sample_round(rng, P, lr, rho, d)
+                    gi = jax.device_put({"neighbors": nb, "weights": wts,
+                                         "lr": np.float32(lr)}, repl_sh)
+                if compiled is None:
+                    watch.rec = setup
+                    with _span("compile", setup):
+                        compiled = step_fn.lower(params, opt_state, batch, gi).compile()
+                    watch.rec = rec
+                    print(f"compiled round program in {setup['compile']:.2f}s")
+                with _span("dispatch", rec):
+                    params, opt_state, m = compiled(params, opt_state, batch, gi)
+                with _span("device_wait", rec):
+                    jax.block_until_ready((params, opt_state, m))
+                with _span("readback", rec):
+                    loss = float(m["loss"])
+                    run.rounds.append(RoundLog(r + 1, loss, rec, nb, wts))
+                with _span("ema", rec):
+                    for i in range(M):
+                        emas[i].update(int(nb[i]),
+                                       link.iteration_time(i, int(nb[i]), now=t_virt))
+                    t_virt += max(link.iteration_time(i, int(nb[i]), now=t_virt)
+                                  for i in range(M))
 
-        if algo == "netmax" and (r + 1) % monitor_every == 0:
-            monitor.collect({i: emas[i].snapshot() for i in range(M)})
-            pol = monitor.step()
-            if np.isfinite(pol.T_convergence):
-                P, rho = pol.P, pol.rho
-                bad = P.sum(axis=1) <= 0
-                P[bad] = np.where(d[bad] > 0, 1.0 / max(M - 1, 1), 0.0)
-            print(f"  [monitor] round {r+1}: lambda2={pol.lambda2:.4f} rho={rho:.4f}")
-        if (r + 1) % log_every == 0 or r == start:
-            print(f"round {r+1:5d} loss={loss:.4f} step_wall={wall:.4f}s "
-                  f"virt={t_virt:.1f}s")
-        if ckpt_dir and (r + 1) % ckpt_every == 0:
-            ckpt.save(ckpt_dir, r + 1, params, opt_state,
-                      monitor_state={"rho": float(rho), "P": P.tolist()},
-                      data_cursor={"round": r + 1})
-            print(f"  [checkpoint] saved round {r+1}")
+                if algo == "netmax" and (r + 1) % monitor_every == 0:
+                    with _span("monitor", rec):
+                        monitor.collect({i: emas[i].snapshot() for i in range(M)})
+                        pol = monitor.step()
+                        if pol.ok:
+                            P, rho = pol.P, pol.rho
+                            bad = P.sum(axis=1) <= 0
+                            P[bad] = np.where(d[bad] > 0, 1.0 / max(M - 1, 1), 0.0)
+                        print(f"  [monitor] round {r+1}: lambda2={pol.lambda2:.4f} "
+                              f"rho={rho:.4f} solves={pol.n_solves} "
+                              f"pivots={pol.n_pivots} warm={pol.n_warm_used} "
+                              f"applied={bool(pol.ok)}")
+                    run.refreshes.append(Refresh(
+                        r + 1, rec["monitor"] * 1e3, pol.n_solves, pol.n_pivots,
+                        pol.n_warm_used, bool(pol.ok)))
+                with _span("log", rec):
+                    if (r + 1) % log_every == 0 or r == start:
+                        step_wall = rec["dispatch"] + rec["device_wait"]
+                        print(f"round {r+1:5d} loss={loss:.4f} step_wall={step_wall:.4f}s "
+                              f"virt={t_virt:.1f}s")
+                if ckpt_dir and (r + 1) % ckpt_every == 0:
+                    with _span("checkpoint", rec):
+                        ckpt.save(ckpt_dir, r + 1, params, opt_state,
+                                  monitor_state={"rho": float(rho), "P": P.tolist()},
+                                  data_cursor={"round": r + 1})
+                        print(f"  [checkpoint] saved round {r+1}")
 
+    run.gc, run.compiles = watch.gc, watch.compiles
+    # Where the slowest rounds went, in the run in which they happened.
+    print(f"slowest rounds: {_slowest(run.rounds)}")
     run.params, run.opt_state = params, opt_state
     return run
 

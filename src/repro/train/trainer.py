@@ -11,6 +11,11 @@ axes); one round = every worker performs one Alg.-2 iteration:
   5. algorithm consensus mix        (the same leaf rule the event-driven
                                      simulator applies — DESIGN.md §1)
 
+Steps 1, 2–3, 4 and 5 run under the named scopes ``forward_backward``,
+``optimizer``, ``gossip_pull`` and ``gossip_mix``: they change only the
+``op_name`` metadata of the compiled program, so a profiler trace can give
+each op's device time to its step.
+
 The strategy (which peers, which weights, which reduction) comes from
 ``repro.algos``: pass an ``Algorithm`` instance or a registry name.  The
 pre-protocol boolean flags on ``TrainStepConfig`` (``allreduce``,
@@ -121,16 +126,18 @@ def make_train_step(
         return microbatch_scan(vgrad, params, batch, cfg.microbatches)
 
     def local_step(params, opt_state, batch, lr):
-        losses, grads = grad_fn(params, batch)
-        if step_cfg.grad_clip:
-            from repro.optim.optimizers import clip_by_global_norm
+        with jax.named_scope("forward_backward"):
+            losses, grads = grad_fn(params, batch)
+        with jax.named_scope("optimizer"):
+            if step_cfg.grad_clip:
+                from repro.optim.optimizers import clip_by_global_norm
 
-            grads, _ = clip_by_global_norm(grads, step_cfg.grad_clip)
-        # Strategy-owned grad reduction: identity for gossip, global mean
-        # for allreduce/ps-sync, group mean for prague.
-        grads = algorithm.transform_grads(grads, M)
-        updates, opt_state = optimizer.update(grads, opt_state, params, lr)
-        x_half = optimizer.apply(params, updates)
+                grads, _ = clip_by_global_norm(grads, step_cfg.grad_clip)
+            # Strategy-owned grad reduction: identity for gossip, global mean
+            # for allreduce/ps-sync, group mean for prague.
+            grads = algorithm.transform_grads(grads, M)
+            updates, opt_state = optimizer.update(grads, opt_state, params, lr)
+            x_half = optimizer.apply(params, updates)
         return losses, x_half, opt_state
 
     def gossip_pull(params, neighbors, perm):
@@ -153,19 +160,21 @@ def make_train_step(
         lr = gossip_in["lr"]
         losses, x_half, opt_state = local_step(params, opt_state, batch, lr)
         if communicates:
-            pulled = gossip_pull(params, gossip_in["neighbors"], perm)
-            if step_cfg.use_gossip_mix_kernel and type(algorithm).delta_transform is Algorithm.delta_transform:
-                from repro.kernels import ops as kops
+            with jax.named_scope("gossip_pull"):
+                pulled = gossip_pull(params, gossip_in["neighbors"], perm)
+            with jax.named_scope("gossip_mix"):
+                if step_cfg.use_gossip_mix_kernel and type(algorithm).delta_transform is Algorithm.delta_transform:
+                    from repro.kernels import ops as kops
 
-                # Fused Pallas mix — only valid for the identity delta
-                # transform (the kernel hard-codes the linear mix).
-                new_params = kops.gossip_mix_tree(
-                    x_half, pulled, gossip_in["weights"]
-                )
-            else:
-                new_params = algorithm.mix_stacked(
-                    x_half, pulled, gossip_in["weights"]
-                )
+                    # Fused Pallas mix — only valid for the identity delta
+                    # transform (the kernel hard-codes the linear mix).
+                    new_params = kops.gossip_mix_tree(
+                        x_half, pulled, gossip_in["weights"]
+                    )
+                else:
+                    new_params = algorithm.mix_stacked(
+                        x_half, pulled, gossip_in["weights"]
+                    )
         else:
             new_params = x_half
         metrics = {"loss": losses.mean(), "loss_per_worker": losses}
