@@ -107,16 +107,16 @@ def reference_rounds(cfg, logs, device, seed):
     """Replays ``logs`` (the sharded run's rounds: same data, same gossip
     draws) one replica at a time on ``device``.  Returns (per-replica params,
     per-round mean loss).  Each replica takes an M=1 local step, then mixes
-    with the pre-round params of the neighbor it drew."""
+    with the pre-round params of the neighbor it drew.  The step donates its
+    params and optimizer state, so a replica whose pre-round params a later
+    replica still pulls steps on a copy of them."""
     M = len(logs[0].neighbors)
     dev = SingleDeviceSharding(device)
     opt, step1 = make_step(cfg, 1, algo="local")  # no gossip at M=1
     mix = jax.jit(lambda h, p, w: jax.tree_util.tree_map(
         lambda a, b: a + w.astype(a.dtype) * (b - a), h, p))
-    p0 = jax.jit(lambda k: init_stacked(cfg, opt, 1, k)[0], out_shardings=dev)(
-        jax.random.PRNGKey(seed))
-    params = [p0] * M  # identical init; the step donates nothing
-    opts = [jax.jit(opt.init, out_shardings=dev)(p0) for _ in range(M)]
+    init = jax.jit(lambda k: init_stacked(cfg, opt, 1, k), out_shardings=dev)
+    params, opts = map(list, zip(*(init(jax.random.PRNGKey(seed)) for _ in range(M))))
     stream = TokenStream(cfg.vocab_size, SEQ, BATCH_PER_WORKER, seed=seed)
     gi = jax.device_put({"neighbors": np.zeros(1, np.int32),
                          "weights": np.zeros(1, np.float32),
@@ -128,7 +128,10 @@ def reference_rounds(cfg, logs, device, seed):
         for i in range(M):
             b = stream.batch(i, log.round - 1)
             batch = jax.device_put({k: b[k][None] for k in ("tokens", "labels")}, dev)
-            x_half, opts[i], m = step1(params[i], opts[i], batch, gi)
+            mine = params[i]
+            if need[i] > 0:  # pulled later: step on a copy
+                mine = jax.tree_util.tree_map(jnp.copy, mine)
+            x_half, opts[i], m = step1(mine, opts[i], batch, gi)
             j = int(log.neighbors[i])
             new[i] = mix(x_half, params[j], jnp.float32(log.weights[i]))
             loss += float(m["loss"]) / M

@@ -1,5 +1,6 @@
 """Compiles for a described TPU v5e (no chip attached): the kernels of the
-main path at qwen1.5-0.5b widths and the chip smoke's whole training round.
+main path at qwen1.5-0.5b widths, the chip smoke's whole training round and
+the benchmark cell's (``qwen15-m2-short512``).
 
 The chip's compiler refuses what interpret mode accepts: tiles that break the
 (8, 128) rule, programs that do not fit HBM.  The topology is described only
@@ -8,6 +9,7 @@ worker given this file loads the TPU library.
 """
 
 import os
+import re
 import sys
 from dataclasses import replace
 from pathlib import Path
@@ -29,6 +31,11 @@ import chip_smoke  # noqa: E402
 
 V5E_HBM_BYTES = 15.75 * 2**30  # what the v5e compiler reports as usable
 QWEN = get_arch("qwen1.5-0.5b")
+# The benchmark cell: f32 at 16 layers, M=2, 2 x 512 tokens a worker.  Its
+# round undonated needs 13.60 GiB and recomputes 14 instructions.
+CELL = replace(QWEN, dtype="float32", n_layers=16)
+CELL_BATCH, CELL_SEQ = 2, 512
+CELL_UNDONATED_BYTES = 13.60 * 2**30
 
 
 @pytest.fixture(scope="module")
@@ -64,11 +71,22 @@ def _hbm_bytes(compiled) -> int:
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
 
 
-def _round_args(cfg, M, opt, state_sh, repl_sh):
+def _state_bytes(params, opt_state) -> int:
+    return sum(l.size * l.dtype.itemsize
+               for l in jax.tree_util.tree_leaves((params, opt_state)))
+
+
+def _remats(compiled) -> list[str]:
+    """Instructions XLA's rematerialization pass recomputes (``.remat<n>``)."""
+    return sorted(set(re.findall(r"[\w.\-]+\.remat\d*\b", compiled.as_text())))
+
+
+def _round_args(cfg, M, opt, state_sh, repl_sh,
+                per_worker=chip_smoke.BATCH_PER_WORKER, seq=chip_smoke.SEQ):
     params, opt_state = abstract_stacked(cfg, opt, M)
     put = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda l: _sds(l.shape, l.dtype, state_sh), t)
-    shape = (M, chip_smoke.BATCH_PER_WORKER, chip_smoke.SEQ)
+    shape = (M, per_worker, seq)
     batch = {k: _sds(shape, jnp.int32, state_sh) for k in ("tokens", "labels")}
     gi = {"neighbors": _sds((M,), jnp.int32, repl_sh),
           "weights": _sds((M,), jnp.float32, repl_sh),
@@ -101,11 +119,41 @@ def test_gossip_mix_rows_compiles(one_chip, M, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_smoke_round_fits_one_chip(one_chip):
-    """The one-chip smoke's round: unreduced qwen1.5-0.5b, M=2, gather pull."""
-    opt, step = make_step(QWEN, 2)
-    compiled = step.lower(*_round_args(QWEN, 2, opt, one_chip, one_chip)).compile()
+@pytest.fixture(scope="module")
+def rounds(one_chip):
+    """name -> (compiled round, its params + optimizer-state bytes): the
+    one-chip smoke's (unreduced qwen1.5-0.5b, M=2, gather pull) and the
+    benchmark cell's."""
+    out = {}
+    for name, cfg, kw in [("smoke", QWEN, {}),
+                          ("cell", CELL, dict(per_worker=CELL_BATCH, seq=CELL_SEQ))]:
+        opt, step = make_step(cfg, 2)
+        args = _round_args(cfg, 2, opt, one_chip, one_chip, **kw)
+        out[name] = step.lower(*args).compile(), _state_bytes(*args[:2])
+    return out
+
+
+def test_smoke_round_fits_one_chip(rounds):
+    compiled, _ = rounds["smoke"]
     assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("name", ["smoke", "cell"])
+def test_round_writes_the_state_in_place(rounds, name):
+    """The round donates params and optimizer state: every byte of them is
+    an output aliased to its input."""
+    compiled, state = rounds[name]
+    assert compiled.memory_analysis().alias_size_in_bytes == state
+
+
+@pytest.mark.parametrize("name", ["smoke", "cell"])
+def test_round_recomputes_nothing(rounds, name):
+    assert _remats(rounds[name][0]) == []
+
+
+def test_cell_round_needs_less_than_undonated(rounds):
+    compiled, _ = rounds["cell"]
+    assert _hbm_bytes(compiled) < CELL_UNDONATED_BYTES
 
 
 def test_four_chip_round_fits_and_gathers(topo):
@@ -118,3 +166,6 @@ def test_four_chip_round_fits_and_gathers(topo):
     compiled = step.lower(*args).compile()
     assert _hbm_bytes(compiled) < V5E_HBM_BYTES
     assert "all-gather" in compiled.as_text()
+    # each chip writes its replica's state in place (a one-replica shard's
+    # small leaves are padded on the device, so the alias reads a little more)
+    assert compiled.memory_analysis().alias_size_in_bytes >= _state_bytes(*args[:2]) // 4
