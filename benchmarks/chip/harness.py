@@ -9,9 +9,17 @@ namespace:
   the start of every round on the host clock (the loop asks it for round
   r's batch first thing in round r);
 - ``init_stacked``: starts every replica from the benchmark's weights
-  (``weights.py``), drawn on the device in the loop's own jitted call;
+  (the model module's ``init``), drawn on the device in the loop's own
+  jitted call;
 - ``make_step``: wraps the compiled round so that, after rounds 1 and 3,
-  the leaf norms of the state are read for the check.
+  the leaf norms of the state are read for the check, and in a traced
+  run the compiled round's text is kept for the scopes of its ops.
+
+The model is the module ``models/<model_type>.py``, found by the
+configuration file's own ``model_type`` as a metric's reader is found by its
+name: its sizes (``Dims``), weights (``shapes``, ``init``), plain forward
+pass (``seq_loss``), model FLOPs and the program's config fields that have
+to match (``program_keys``).  Nothing here names a model.
 
 The loop takes a round count, so a short call in set-up measures the round
 time and sets the count; the window then holds the rounds of a second call
@@ -40,8 +48,8 @@ import numpy as np
 
 import check
 import reference
+import spans
 import xplane as trace_mod
-import weights
 from flops import round_flops
 from peaks import peaks
 from tokens import token_pool
@@ -80,41 +88,66 @@ def resolve(name: str, trace: bool, bench: dict | None = None) -> Cell:
     for m in metrics:
         if not (HERE / "metrics" / f"{m['name']}.py").is_file():
             raise FileNotFoundError(f"no reader metrics/{m['name']}.py")
+    config = json.loads((ROOT / conf["file"]).read_text())
+    model_of(config)
     return Cell(
-        name=name, chips=w["chips"],
-        config=json.loads((ROOT / conf["file"]).read_text()),
+        name=name, chips=w["chips"], config=config,
         traffic=json.loads((HERE / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=json.loads((HERE / "limits" / f"{name}.json").read_text()),
         metrics=metrics,
     )
 
 
+def _load(kind: str, name: str):
+    """The module ``<kind>/<name>.py`` of the benchmark's directory, loaded
+    once a process (a model module is a static argument of jitted calls)."""
+    path = HERE / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind}/{name}.py in {HERE}")
+    key = f"chip_{kind}_{name}"
+    mod = sys.modules.get(key)
+    if mod is None or mod.__file__ != str(path):
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    return mod
+
+
+def model_of(conf: dict):
+    """The module that describes a configuration file's model: the file
+    ``models/<model_type>.py``, by the published ``model_type`` key."""
+    return _load("models", conf["model_type"])
+
+
+def _field(cfg, key: str):
+    for part in key.split("."):
+        cfg = getattr(cfg, part)
+    return cfg
+
+
 def program_config(conf: dict):
     """The program's ArchConfig for a configuration file, checked against
-    the file's published keys."""
+    the file's published keys: every field that the model module's
+    ``program_keys`` names (``moe.top_k`` names a field of a nested
+    config).  In the file's ``program.set`` a nested config is given as a
+    dict of the fields that change."""
     from repro.configs.base import get_arch
 
-    cfg = dataclasses.replace(get_arch(conf["program"]["registered"]),
-                              **conf["program"]["set"])
-    d = weights.Dims.from_config(conf)
-    want = dict(n_layers=d.layers, d_model=d.d_model, n_heads=d.heads,
-                n_kv_heads=d.kv_heads, hd=d.head_dim, d_ff=d.d_ff,
-                vocab_size=d.vocab, rope_theta=d.rope_theta,
-                qkv_bias=d.qkv_bias, tie_embeddings=True, family="dense",
-                n_vis_tokens=0, norm="rmsnorm", activation="swiglu",
-                pad_heads=0, pad_kv_heads=0)
-    bad = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
+    base = get_arch(conf["program"]["registered"])
+    cfg = dataclasses.replace(base, **{
+        k: dataclasses.replace(getattr(base, k), **v) if isinstance(v, dict) else v
+        for k, v in conf["program"]["set"].items()})
+    model = model_of(conf)
+    want = model.program_keys(model.Dims.from_config(conf))
+    bad = {k: (_field(cfg, k), v) for k, v in want.items() if _field(cfg, k) != v}
     if bad:
         raise ValueError(f"program config differs from the file: {bad}")
     return cfg
 
 
 def _reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"chip_metric_{name}", HERE / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load("metrics", name).read
 
 
 @jax.jit
@@ -129,10 +162,9 @@ def _replica_norms(tree):
 class Hooks:
     """The stand-ins' shared state for one ``train`` call."""
 
-    def __init__(self, pool, dims, dtype, seed, monitor_every):
-        self.pool, self.dims, self.dtype = pool, dims, dtype
+    def __init__(self, pool, model, dims, dtype, seed):
+        self.pool, self.model, self.dims, self.dtype = pool, model, dims, dtype
         self.key = jax.random.PRNGKey(seed)
-        self.monitor_every = monitor_every
         self.reset()
 
     def reset(self, probe=False, trace_rounds=None, trace_dir=None):
@@ -140,11 +172,12 @@ class Hooks:
         self.probe, self.calls = probe, 0
         self.mom1 = self.change = None
         self.trace_rounds, self.trace_dir = trace_rounds, trace_dir
+        self.text = None  # the compiled round's text, in a traced call
 
     # -- TokenStream ------------------------------------------------------
     def stream(self, vocab_size, seq_len, batch_size, seed=0):
         W, K, B, S1 = self.pool.shape
-        if (vocab_size, seq_len, batch_size) != (self.dims.vocab, S1 - 1, B):
+        if (seq_len, batch_size) != (S1 - 1, B):
             raise ValueError("the loop asked for other batches than the cell's")
         return self
 
@@ -167,7 +200,7 @@ class Hooks:
 
     # -- init_stacked -----------------------------------------------------
     def init_stacked(self, cfg, optimizer, M, key):
-        p1 = weights.init(self.dims, key, self.dtype)
+        p1 = self.model.init(self.dims, key, self.dtype)
         params = jax.tree_util.tree_map(
             lambda x: jnp.array(jnp.broadcast_to(x[None], (M,) + x.shape)), p1)
         return params, optimizer.init(params)
@@ -191,7 +224,10 @@ class Hooks:
                 self.low = low
 
             def compile(self):
-                return Compiled(self.low.compile())
+                c = self.low.compile()
+                if hooks.trace_rounds is not None:
+                    hooks.text = c.as_text()
+                return Compiled(c)
 
         class Jitted:
             def __init__(self, fn):
@@ -212,14 +248,15 @@ class Hooks:
         if self.calls == 1:
             self.mom1 = np.asarray(_replica_norms(opt_state["m"]))
         if self.calls == CHECKED_ROUNDS:
-            self.change = np.asarray(_change_norms(params, self.key, self.dims, self.dtype))
+            self.change = np.asarray(_change_norms(
+                params, self.key, self.model, self.dims, self.dtype))
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-def _change_norms(params, key, dims, dtype):
+@partial(jax.jit, static_argnums=(2, 3, 4))
+def _change_norms(params, key, model, dims, dtype):
     """(M, leaves) norms of each replica's change from the initial weights,
     which are drawn again from ``key`` inside the program."""
-    p0 = weights.init(dims, key, dtype)
+    p0 = model.init(dims, key, dtype)
     return _replica_norms(jax.tree_util.tree_map(
         lambda p, q: p.astype(jnp.float32) - q.astype(jnp.float32)[None], params, p0))
 
@@ -263,12 +300,13 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
              t_start: float, log=sys.stderr) -> dict:
     """One run; returns the result line's object (``checks`` last)."""
     conf, tr = cell.config, cell.traffic
-    dims = weights.Dims.from_config(conf)
+    model = model_of(conf)
+    dims = model.Dims.from_config(conf)
     arch = program_config(conf)
     dtype = jnp.dtype(arch.dtype)
     M, B, S = tr["workers"], tr["batch_per_worker"], tr["seq_len"]
-    pool = token_pool(seed, dims.vocab, M, tr["pool_batches"], B, S)
-    hooks = Hooks(pool, dims, dtype, seed, tr["monitor_every"])
+    pool = token_pool(seed, arch.vocab_size, M, tr["pool_batches"], B, S)
+    hooks = Hooks(pool, model, dims, dtype, seed)
     compiles = CompileCounter()
     kw = dict(workers=M, devices=devices, seq=S, batch_per_worker=B, lr=tr["lr"],
               algo=tr["algo"], gossip=tr["gossip"], seed=seed,
@@ -307,17 +345,24 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
             tokens_per_round=M * B * S,
             chips=len(devices),
             setup_s=s[WINDOW_FIRST] - t_start,
-            round_flops=round_flops(dims, M, B, S),
+            round_flops=round_flops(model, dims, M, B, S),
             peaks=peaks(kind) if devices[0].platform == "tpu" else None,
             memory_peak_bytes=mem,
             trace=None,
+            spans=None,
+            model=model, dims=dims, workers=M, batch_per_worker=B, seq_len=S,
         )
         result_extra = {}
         if trace:
-            ctx.trace = trace_mod.reduce(trace_mod.read_rows(trace_dir))
+            rows = trace_mod.read_rows(trace_dir)
+            ctx.trace = trace_mod.reduce(rows)
+            ctx.spans = spans.reduce(rows, spans.op_scopes(hooks.text))
+            del rows
             if ctx.trace is not None:
                 t = ctx.trace
-                result_extra["breakdown"] = trace_mod.breakdown(t, tr["monitor_every"])
+                br = result_extra["breakdown"] = trace_mod.breakdown(t, tr["monitor_every"])
+                if ctx.spans is not None:
+                    br["idle_by_span"] = ctx.spans["idle_by_span"][:10]
                 result_extra["busy_s"] = sum(c["busy_ns"] for c in t["chips"]) / len(t["chips"]) * 1e-9
                 result_extra["window_s"] = t["window_ns"] * 1e-9
         metrics = {}
@@ -330,9 +375,9 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool, devices,
               file=log)
 
         t_ref = time.perf_counter()
-        p0 = jax.jit(weights.init, static_argnums=(0, 2))(dims, hooks.key, dtype)
+        p0 = jax.jit(model.init, static_argnums=(0, 2))(dims, hooks.key, dtype)
         ref = reference.rounds(
-            dims, p0, [pool[:, r % pool.shape[1]] for r in range(CHECKED_ROUNDS)],
+            model, dims, p0, [pool[:, r % pool.shape[1]] for r in range(CHECKED_ROUNDS)],
             draws, lr=tr["lr"], momentum=tr["momentum"],
             weight_decay=tr["weight_decay"], devices=devices)
         del p0

@@ -35,13 +35,12 @@ import numpy as np  # noqa: E402
 import check  # noqa: E402
 import harness  # noqa: E402
 import reference  # noqa: E402
-import weights  # noqa: E402
 from tokens import token_pool  # noqa: E402
 
 
-def leaf_names(dims) -> list[str]:
+def leaf_names(model, dims) -> list[str]:
     flat, _ = jax.tree_util.tree_flatten_with_path(
-        weights.shapes(dims), is_leaf=lambda x: isinstance(x, tuple))
+        model.shapes(dims), is_leaf=lambda x: isinstance(x, tuple))
     return [jax.tree_util.keystr(p) for p, _ in flat]
 
 
@@ -49,11 +48,12 @@ def program_rounds(cell, seed, devices):
     """The program's first rounds from ``seed``: (prog numbers' inputs,
     draws, pool)."""
     conf, tr = cell.config, cell.traffic
-    dims = weights.Dims.from_config(conf)
+    model = harness.model_of(conf)
+    dims = model.Dims.from_config(conf)
     arch = harness.program_config(conf)
     M, B, S = tr["workers"], tr["batch_per_worker"], tr["seq_len"]
-    pool = token_pool(seed, dims.vocab, M, tr["pool_batches"], B, S)
-    hooks = harness.Hooks(pool, dims, jax.numpy.dtype(arch.dtype), seed, tr["monitor_every"])
+    pool = token_pool(seed, arch.vocab_size, M, tr["pool_batches"], B, S)
+    hooks = harness.Hooks(pool, model, dims, jax.numpy.dtype(arch.dtype), seed)
     hooks.reset(probe=True)
     with harness.stand_ins(hooks) as train, contextlib.redirect_stdout(sys.stderr):
         run = train(arch, rounds=harness.CHECKED_ROUNDS, workers=M, devices=devices,
@@ -67,11 +67,11 @@ def program_rounds(cell, seed, devices):
 
 def replay(cell, hooks, pool, draws, devices, **kw):
     tr = cell.traffic
-    p0 = jax.jit(weights.init, static_argnums=(0, 2))(hooks.dims, hooks.key, hooks.dtype)
+    p0 = jax.jit(hooks.model.init, static_argnums=(0, 2))(hooks.dims, hooks.key, hooks.dtype)
+    batches = [pool[:, r % pool.shape[1]] for r in range(harness.CHECKED_ROUNDS)]
     return reference.rounds(
-        hooks.dims, p0, [pool[:, r % pool.shape[1]] for r in range(harness.CHECKED_ROUNDS)],
-        draws, lr=tr["lr"], momentum=tr["momentum"], weight_decay=tr["weight_decay"],
-        devices=devices, **kw)
+        hooks.model, hooks.dims, p0, batches, draws, lr=tr["lr"],
+        momentum=tr["momentum"], weight_decay=tr["weight_decay"], devices=devices, **kw)
 
 
 def main(argv=None) -> int:
@@ -105,7 +105,7 @@ def main(argv=None) -> int:
                       ("fault_no_pull", replay(cell, hooks, pool, nopull, devices))]
             if half is not None:
                 lines.append(("fault_half_batch", half))
-        names = leaf_names(hooks.dims)
+        names = leaf_names(hooks.model, hooks.dims)
         for source, got in lines:
             moved = np.asarray(ref["change"]) > 0
             unmoved = sorted({names[j] for i, j in zip(*np.nonzero(

@@ -1,21 +1,25 @@
-"""Plain float32 reference of a NetMax round for a Qwen2-family decoder.
+"""Plain float32 reference of a NetMax round, for any model the benchmark
+describes, and the blocks a model's own reference is built from.
 
-Written from the published Qwen2 model (RMSNorm, rotary embeddings with
-``rotate_half``, grouped-query attention with q/k/v biases, SwiGLU MLP,
-tied embeddings) and the NetMax round (Alg. 2: local SGD step with momentum
-and weight decay, then a pull of the drawn neighbour's pre-round replica and
-the mix ``x + w (pulled - x)``).  It imports nothing of the program.
+The round is Alg. 2: a local SGD step with momentum and weight decay, then a
+pull of the drawn neighbour's pre-round replica and the mix
+``x + w (pulled - x)``.  The model is a module of ``models/`` (found by the
+configuration's ``model_type``), whose ``seq_loss(d, quant, params, tokens,
+labels)`` is the forward pass and loss of one sequence.  It imports nothing
+of the program.
 
 Every matrix product runs at ``precision=highest``.  To fit at the timed
-sizes it works one sequence at a time, recomputes each layer in the
-backward pass, and takes attention and the loss head in blocks of
-``BLOCK`` positions; none of that changes the mathematics.
+sizes it works one sequence at a time, and a model's ``seq_loss`` recomputes
+each layer in the backward pass and takes attention and the loss head in
+blocks of ``BLOCK`` positions (``attention``, ``blocked_loss``); none of that
+changes the mathematics.
 
 The control is the reference one precision below the configuration's:
 ``quant="int8"`` (below bfloat16) rounds each matrix product's operands to
 symmetric int8 with one scale per tensor (straight-through in the backward
 pass); ``quant="bf16"`` (below float32) rounds them to bfloat16 and keeps
-the weights in bfloat16 between updates.
+the weights in bfloat16 between updates.  A model's products go through
+``mm`` so that the control reaches all of them.
 """
 
 from __future__ import annotations
@@ -25,8 +29,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from weights import Dims
 
 BLOCK = 512
 HI = jax.lax.Precision.HIGHEST
@@ -39,7 +41,8 @@ def _q8(x):
     return x + jax.lax.stop_gradient(jnp.round(x / s) * s - x)
 
 
-def _mm(spec, a, b, quant):
+def mm(spec, a, b, quant):
+    """``einsum(spec, a, b)`` at ``highest``, or in the control's precision."""
     if quant == "int8":
         a, b = _q8(a), _q8(b)
     if quant == "bf16":
@@ -53,11 +56,11 @@ def _store(x, quant):
     return jax.lax.reduce_precision(x, 8, 7) if quant == "bf16" else x
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (S, H, hd); rotate_half form, as the published model applies it."""
     S, _, hd = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -68,7 +71,7 @@ def _rope(x, theta):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-def _attention(q, k, v, quant):
+def attention(q, k, v, quant):
     """Causal GQA for one sequence; q (S, H, hd), k/v (S, Hk, hd)."""
     S, H, hd = q.shape
     G = H // k.shape[1]
@@ -80,55 +83,35 @@ def _attention(q, k, v, quant):
     @jax.checkpoint
     def block(i):
         qb = jax.lax.dynamic_slice_in_dim(q, i * blk, blk, 0)
-        s = _mm("qhd,khd->hqk", qb, k, quant) / np.sqrt(hd)
+        s = mm("qhd,khd->hqk", qb, k, quant) / np.sqrt(hd)
         qpos = i * blk + jnp.arange(blk)
         s = jnp.where(qpos[None, :, None] >= kpos[None, None, :], s, -jnp.inf)
-        return _mm("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v, quant)
+        return mm("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v, quant)
 
     out = jax.lax.map(block, jnp.arange(S // blk))
     return out.reshape(S, H * hd)
 
 
-def _layer(d: Dims, quant, x, lp):
-    S = x.shape[0]
-    a = lp["attn"]
-    h = _rms(x, lp["ln1"]["scale"], d.rms_eps)
-    q, k, v = (_mm("sd,de->se", h, a[w], quant) for w in ("wq", "wk", "wv"))
-    if d.qkv_bias:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = _rope(q.reshape(S, d.heads, d.head_dim), d.rope_theta)
-    k = _rope(k.reshape(S, d.kv_heads, d.head_dim), d.rope_theta)
-    v = v.reshape(S, d.kv_heads, d.head_dim)
-    x = x + _mm("se,ed->sd", _attention(q, k, v, quant), a["wo"], quant)
-    m = lp["mlp"]
-    h = _rms(x, lp["ln2"]["scale"], d.rms_eps)
-    g = jax.nn.silu(_mm("sd,df->sf", h, m["w_gate"], quant))
-    u = _mm("sd,df->sf", h, m["w_up"], quant)
-    return x + _mm("sf,fd->sd", g * u, m["w_down"], quant), None
-
-
-def seq_loss(d: Dims, quant, params, tokens, labels):
-    """Mean next-token cross-entropy of one sequence (tokens, labels: (S,))."""
-    table = params["embed"]["table"]
-    x = table[tokens]
-    x, _ = jax.lax.scan(jax.checkpoint(partial(_layer, d, quant)), x, params["blocks"])
-    x = _rms(x, params["final_norm"]["scale"], d.rms_eps)
+def blocked_loss(x, labels, logits):
+    """Mean next-token cross-entropy of one sequence from its final hidden
+    states ``x`` (S, D), in blocks of ``BLOCK`` positions; ``logits(xb)``
+    gives a block's (blk, vocab) logits."""
     S = x.shape[0]
     blk = min(BLOCK, S)
 
     @jax.checkpoint
     def nll(xb, lb):
-        logits = _mm("sd,vd->sv", xb, table, quant)
-        gold = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
-        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - gold)
+        z = logits(xb)
+        gold = jnp.take_along_axis(z, lb[:, None], axis=-1)[:, 0]
+        return jnp.sum(jax.nn.logsumexp(z, axis=-1) - gold)
 
     xs, ls = x.reshape(S // blk, blk, -1), labels.reshape(S // blk, blk)
     return jnp.sum(jax.lax.map(lambda a: nll(*a), (xs, ls))) / S
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _seq_grad(d, quant, params, tokens, labels):
-    return jax.value_and_grad(partial(seq_loss, d, quant))(params, tokens, labels)
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _seq_grad(model, d, quant, params, tokens, labels):
+    return jax.value_and_grad(partial(model.seq_loss, d, quant))(params, tokens, labels)
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -158,12 +141,13 @@ def leaf_norms(tree):
                       for x in jax.tree_util.tree_leaves(tree)])
 
 
-def grad(d: Dims, params, tokens, labels, quant=None):
-    """(mean loss, mean gradient) over the rows of one worker's batch."""
+def grad(model, d, params, tokens, labels, quant=None):
+    """(mean loss, mean gradient) of ``model`` over the rows of one worker's
+    batch."""
     loss_acc, grad_acc = None, None
     for b in range(tokens.shape[0]):
         with jax.default_matmul_precision("highest"):
-            loss, g = _seq_grad(d, quant, params, tokens[b], labels[b])
+            loss, g = _seq_grad(model, d, quant, params, tokens[b], labels[b])
         if grad_acc is None:
             loss_acc, grad_acc = loss, g
         else:
@@ -171,9 +155,10 @@ def grad(d: Dims, params, tokens, labels, quant=None):
     return loss_acc / tokens.shape[0], grad_acc
 
 
-def rounds(d: Dims, p0, batches, draws, *, lr, momentum, weight_decay,
+def rounds(model, d, p0, batches, draws, *, lr, momentum, weight_decay,
            devices, quant=None, rows=None):
-    """Replay NetMax rounds from the weights ``p0`` (one worker's tree).
+    """Replay NetMax rounds of ``model`` (a module of ``models/``, with its
+    ``Dims`` ``d``) from the weights ``p0`` (one worker's tree).
 
     ``batches[r]``: (M, B, S + 1) token rows of round r; ``draws[r]``:
     (neighbours (M,), weights (M,)).  Worker i lives on
@@ -196,7 +181,7 @@ def rounds(d: Dims, p0, batches, draws, *, lr, momentum, weight_decay,
         for i in range(M):
             toks = batch[i] if rows is None else batch[i][:rows]
             toks = jax.device_put(toks, dev[i])
-            li, g = grad(d, p[i], toks[:, :-1], toks[:, 1:], quant)
+            li, g = grad(model, d, p[i], toks[:, :-1], toks[:, 1:], quant)
             loss += float(li) / M
             if r == 0:
                 grad1 = (grad1 or []) + [np.asarray(leaf_norms(g)) / toks.shape[0]]

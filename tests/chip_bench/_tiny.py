@@ -18,9 +18,9 @@ def tiny_cell(name: str, kv_heads: int = 4) -> harness.Cell:
     float32 model of the same family at a small size (GQA when
     ``kv_heads`` < 4)."""
     real = harness.resolve(name, False, BENCHMARK)
-    conf = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-            "num_attention_heads": 4, "num_key_value_heads": kv_heads,
-            "vocab_size": 256, "rope_theta": 1e6, "rms_norm_eps": 1e-6,
+    conf = {"model_type": real.config["model_type"], "hidden_size": 64,
+            "intermediate_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4,
+            "num_key_value_heads": kv_heads, "vocab_size": 256, "rope_theta": 1e6, "rms_norm_eps": 1e-6,
             "hidden_act": "silu", "initializer_range": 0.02,
             "tie_word_embeddings": True, "qkv_bias": True,
             "program": {"registered": real.config["program"]["registered"], "set": dict(

@@ -13,7 +13,6 @@ import pytest
 from _paths import BENCH, ROOT
 
 import harness  # noqa: E402
-import weights  # noqa: E402
 from tokens import token_pool  # noqa: E402
 
 BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -65,38 +64,6 @@ def test_token_pool_is_a_function_of_the_seed():
     assert a.min() >= 0 and a.max() < 151936
     rows = a.reshape(-1, 65)
     assert len({r.tobytes() for r in rows}) == len(rows)  # every row differs
-
-
-def _tiny_conf():
-    return {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-            "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 256,
-            "rope_theta": 1e6, "rms_norm_eps": 1e-6, "hidden_act": "silu",
-            "initializer_range": 0.02, "tie_word_embeddings": True, "qkv_bias": True,
-            "program": {"registered": "internvl2-1b", "set": dict(
-                name="tiny", family="dense", n_vis_tokens=0, n_layers=2, d_model=64,
-                n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256, head_dim=16,
-                qkv_bias=True)}}
-
-
-def test_benchmark_weights_have_the_programs_layout():
-    import jax
-    from repro.models import lm
-
-    conf = _tiny_conf()
-    cfg = harness.program_config(conf)
-    dims = weights.Dims.from_config(conf)
-    ours = jax.eval_shape(lambda: weights.init(dims, jax.random.PRNGKey(0), jax.numpy.bfloat16))
-    theirs = lm.abstract_params(cfg)
-    assert jax.tree_util.tree_structure(ours) == jax.tree_util.tree_structure(theirs)
-    for a, b in zip(jax.tree_util.tree_leaves(ours), jax.tree_util.tree_leaves(theirs)):
-        assert (a.shape, a.dtype) == (b.shape, b.dtype)
-
-
-def test_program_config_refuses_a_file_that_differs():
-    conf = _tiny_conf()
-    conf["intermediate_size"] = 256
-    with pytest.raises(ValueError):
-        harness.program_config(conf)
 
 
 def _run(args, cwd):

@@ -11,7 +11,6 @@ from _tiny import BENCHMARK, SEED, run, tiny_cell
 import check  # noqa: E402
 import harness  # noqa: E402
 import reference  # noqa: E402
-import weights  # noqa: E402
 from tokens import token_pool  # noqa: E402
 
 CELL = BENCHMARK["workloads"][0]["name"]
@@ -33,17 +32,18 @@ def test_control_fails_the_limits():
 
     cell = tiny_cell(CELL)
     tr = cell.traffic
-    dims = weights.Dims.from_config(cell.config)
+    model = harness.model_of(cell.config)
+    dims = model.Dims.from_config(cell.config)
     M, B, S = tr["workers"], tr["batch_per_worker"], tr["seq_len"]
     pool = token_pool(SEED, dims.vocab, M, 3, B, S)
     draws = [(np.array([1, 0]), np.full(2, 0.25, np.float32))] * 3
-    p0 = weights.init(dims, jax.random.PRNGKey(SEED), np.float32)
+    p0 = model.init(dims, jax.random.PRNGKey(SEED), np.float32)
     kw = dict(lr=tr["lr"], momentum=tr["momentum"], weight_decay=tr["weight_decay"],
               devices=jax.devices()[:1])
     batches = [pool[:, r] for r in range(3)]
-    ref = reference.rounds(dims, p0, batches, draws, **kw)
+    ref = reference.rounds(model, dims, p0, batches, draws, **kw)
     stated = harness.resolve(CELL, False, BENCHMARK).config["torch_dtype"]
-    control = reference.rounds(dims, p0, batches, draws,
+    control = reference.rounds(model, dims, p0, batches, draws,
                                quant=reference.CONTROL[stated], **kw)
     nums = check.numbers(control, ref)
     assert not check.verdict(nums, cell.limits), nums

@@ -176,3 +176,40 @@ def test_span_readers_on_a_program_without_spans(name):
     rows = [r for r in _fixture() if r[2] not in spans.SPANS]
     ctx = SimpleNamespace(trace=xplane.reduce(rows), spans=spans.reduce(rows, {}))
     assert harness._reader(name)(ctx) is None
+
+
+def test_a_traced_run_hands_the_readers_its_spans(monkeypatch):
+    """A whole traced run on the CPU, whose trace holds no device: the
+    harness reads the trace's rows (here the fixture's) and reduces them
+    with the scopes of the compiled round's own text."""
+    import time
+
+    import jax
+
+    import harness
+    from _tiny import BENCHMARK, SEED, tiny_cell
+
+    texts = []
+
+    def op_scopes(text):
+        texts.append(text)
+        return SCOPES
+
+    monkeypatch.setattr(harness.trace_mod, "read_rows", lambda trace_dir: _fixture())
+    monkeypatch.setattr(harness.spans, "op_scopes", op_scopes)
+    name = BENCHMARK["workloads"][0]["name"]
+    cell = tiny_cell(name)
+    cell.metrics = harness.resolve(name, True, BENCHMARK).metrics
+    out = harness.run_cell(cell, seed=SEED, seconds=0.3, trace=True,
+                           devices=jax.devices()[:1], t_start=time.perf_counter())
+    assert out["correct"] is True
+    assert len(texts) == 1 and "forward_backward" in texts[0] and "gossip_pull" in texts[0]
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert got["fwd_bwd_ms"] == pytest.approx(50 * US * 1e-6)
+    assert got["optimizer_ms"] == pytest.approx(12 * US * 1e-6)
+    assert got["gossip_ms"] == pytest.approx(3 * US * 1e-6)
+    assert got["loop_host_ms"] == pytest.approx((40 + 10) * US * 1e-6 / 2)
+    assert got["monitor_refresh_ms"] == pytest.approx(10 * US * 1e-6)
+    idle = dict(out["breakdown"]["idle_by_span"])
+    assert idle["stage"] == pytest.approx(37 * US * 1e-9)
+    assert out["device"]["window_s"] == pytest.approx(200 * US * 1e-9)

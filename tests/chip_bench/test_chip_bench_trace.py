@@ -6,10 +6,12 @@ import pytest
 from _paths import BENCH
 
 import flops  # noqa: E402
+import harness  # noqa: E402
 import xplane  # noqa: E402
 from peaks import peaks  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
-from weights import Dims  # noqa: E402
+
+QWEN2 = harness.model_of({"model_type": "qwen2"})
 
 OPS, MODS = xplane.OPS_LINE, xplane.MODULES_LINE
 
@@ -57,8 +59,6 @@ def test_reduction_of_a_hand_built_trace():
 
 
 def test_metric_readers_on_the_fixture():
-    import harness
-
     red = xplane.reduce(_fixture())
     ctx = SimpleNamespace(trace=red, chips=2, round_flops=2 * 197e12 * 80e-6,
                           peaks=peaks("TPU v5 lite"), memory_peak_bytes=3 * 2**30,
@@ -104,23 +104,23 @@ def test_unknown_device_kind_is_an_error():
 
 
 def test_model_flops_against_a_hand_count():
-    d = Dims(layers=2, d_model=8, heads=2, kv_heads=1, head_dim=4, d_ff=16, vocab=10,
+    d = QWEN2.Dims(layers=2, d_model=8, heads=2, kv_heads=1, head_dim=4, d_ff=16, vocab=10,
              rope_theta=1e4, rms_eps=1e-6, init_std=0.02, qkv_bias=True)
     S = 3
     # per layer: q 8x8, k 8x4, v 8x4, o 8x8, mlp 3 x 8x16 = 64+32+32+64+384 = 576
     matmuls = 2 * S * (2 * 576 + 8 * 10)
     # causal pairs 3+2+1 = 6, q width 8: scores and values, 2 FLOPs each
     attention = 2 * 2 * 2 * 6 * 8
-    assert flops.forward_flops_per_sequence(d, S) == matmuls + attention
-    assert flops.round_flops(d, 2, 3, S) == 3 * 2 * 3 * (matmuls + attention)
+    assert QWEN2.forward_flops_per_sequence(d, S) == matmuls + attention
+    assert flops.round_flops(QWEN2, d, 2, 3, S) == 3 * 2 * 3 * (matmuls + attention)
 
 
 def test_model_flops_of_the_configurations():
     import json
 
     conf = json.loads((BENCH / "configs" / "qwen1.5-0.5b.json").read_text())
-    d = Dims.from_config(dict(conf, num_hidden_layers=24))  # the published depth
+    d = QWEN2.Dims.from_config(dict(conf, num_hidden_layers=24))  # the published depth
     # 463,987,712 parameters a replica, less the norms and biases, and at
     # seq 1 one query-key pair a layer
     weights_ = 463_987_712 - 24 * (2 * 1024 + 3 * 1024) - 1024
-    assert flops.forward_flops_per_sequence(d, 1) == 2 * weights_ + 24 * 4 * 1024
+    assert QWEN2.forward_flops_per_sequence(d, 1) == 2 * weights_ + 24 * 4 * 1024
